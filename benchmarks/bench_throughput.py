@@ -102,21 +102,6 @@ def _tree_bytes(tree) -> int:
                    for leaf in jax.tree.leaves(tree)))
 
 
-def _peak_bytes(compiled) -> int | None:
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return None
-    if ma is None:
-        return None
-    sizes = [getattr(ma, k, None) for k in
-             ("temp_size_in_bytes", "output_size_in_bytes",
-              "argument_size_in_bytes")]
-    if any(s is None for s in sizes):
-        return None
-    return int(sum(sizes))
-
-
 def _measure(fn, axes) -> dict:
     """Compile + steady-state timings and byte counts for one sweep mode.
 
@@ -128,7 +113,7 @@ def _measure(fn, axes) -> dict:
     t0 = time.perf_counter()
     compiled = fn.lower(*axes).compile()
     compile_s = time.perf_counter() - t0
-    peak = _peak_bytes(compiled)
+    peak = sweep.peak_bytes(compiled)
     jax.block_until_ready(compiled(*axes))   # warm dispatch
     t0 = time.perf_counter()
     for _ in range(STEADY_ITERS):

@@ -39,6 +39,8 @@ import pathlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BASELINES_DIR = pathlib.Path(__file__).resolve().parent / "baselines"
 
 
@@ -101,6 +103,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="also write a machine-readable per-suite report "
                          "(pass/fail + wall clock) to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache(BASELINES_DIR.parent.parent)
 
     suites = discover()
     if args.only is not None and args.only not in suites:

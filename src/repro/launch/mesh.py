@@ -21,15 +21,9 @@ from jax.sharding import Mesh
 
 
 def _mk_mesh(shape: tuple, axes: tuple) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the jax version has
-    them; plain device-grid ``Mesh`` otherwise (jax < 0.5 has no
-    ``jax.sharding.AxisType``)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    devs = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return Mesh(devs, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -80,7 +80,6 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ..checkpoint import checkpointer
@@ -570,10 +569,10 @@ def _sweep_callable(workload, cfg: runner.SimConfig,
     if mesh is not None:
         p_b = PartitionSpec(mesh.axis_names[0])
         p_r = PartitionSpec()
-        batched = shard_map(
+        batched = jax.shard_map(
             batched, mesh=mesh,
             in_specs=(p_b,) * 6 + (p_r, p_r) + ((p_b,) if chaos else ()),
-            out_specs=p_b, check_rep=False)
+            out_specs=p_b, check_vma=False)
     donate_kw = dict(donate_argnums=(0, 1, 2, 3, 4, 5)) if donate else {}
     fn = jax.jit(batched, **donate_kw)
     runner._cache_put(key, fn)
@@ -700,22 +699,16 @@ class SweepReport:
         export.write_trace(path, export.sweep_trace_events(self.chunks))
 
 
-def _peak_bytes(compiled) -> int | None:
-    """XLA's peak-memory estimate for one compiled chunk executable
-    (temp + output + argument bytes; None where the backend offers no
-    analysis) — same convention as ``benchmarks.bench_throughput``."""
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return None
+def peak_bytes(compiled) -> int | None:
+    """XLA's peak-memory estimate for one compiled executable: temp +
+    output + argument bytes.  ``None`` only where the backend offers no
+    analysis (``Compiled.memory_analysis`` returns ``None`` there); any
+    other failure is raised."""
+    ma = compiled.memory_analysis()
     if ma is None:
         return None
-    sizes = [getattr(ma, k, None) for k in
-             ("temp_size_in_bytes", "output_size_in_bytes",
-              "argument_size_in_bytes")]
-    if any(s is None for s in sizes):
-        return None
-    return int(sum(sizes))
+    return int(ma.temp_size_in_bytes + ma.output_size_in_bytes
+               + ma.argument_size_in_bytes)
 
 
 # --------------------------------------------------------------------------
@@ -934,7 +927,7 @@ def sweep(spec: SweepSpec, cfg: runner.SimConfig):
                 t0 = time.perf_counter()
                 compiled = fn.lower(*part, sched, pp, *fpart).compile()
                 compile_s = time.perf_counter() - t0
-                peak = _peak_bytes(compiled)
+                peak = peak_bytes(compiled)
             t0 = time.perf_counter()
             res = jax.block_until_ready(compiled(*part, sched, pp, *fpart))
             profiles.append(ChunkProfile(
@@ -1014,7 +1007,7 @@ def _run_streamed(fn, axes: SweepAxes, sched, pp, b: int, chunk: int,
                 t0 = time.perf_counter()
                 compiled = fn.lower(*part, sched, pp, *fpart).compile()
                 compile_s = time.perf_counter() - t0
-                peak = _peak_bytes(compiled)
+                peak = peak_bytes(compiled)
             t0 = time.perf_counter()
             res = jax.block_until_ready(compiled(*part, sched, pp, *fpart))
             execute_s = time.perf_counter() - t0
