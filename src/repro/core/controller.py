@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from . import aimd as aimd_lib
@@ -146,14 +147,15 @@ def step(state: ControllerState,
     # there with inflated covariance instead of silently standing still.
     k_probe = None
     if cfg.predictor == "kalman":
-        if obs is not None and obs.want_kalman:
-            # Innovation/NIS from the *pre-update* bank — the residual
-            # eq. 8 is about to correct with (trace-time gated: probe-free
-            # configs compile the exact historical update).
-            k_probe = kalman.probe(state.kf, meas_mask, p)
-        kf = kalman.step(state.kf, b_meas, meas_mask, p,
-                         use_kernel=cfg.kalman_kernel,
-                         dropped=meas_dropped)
+        with jax.named_scope("kalman"):
+            if obs is not None and obs.want_kalman:
+                # Innovation/NIS from the *pre-update* bank — the residual
+                # eq. 8 is about to correct with (trace-time gated:
+                # probe-free configs compile the exact historical update).
+                k_probe = kalman.probe(state.kf, meas_mask, p)
+            kf = kalman.step(state.kf, b_meas, meas_mask, p,
+                             use_kernel=cfg.kalman_kernel,
+                             dropped=meas_dropped)
         arma = state.arma
         b_hat, reliable = kf.b_hat, kf.reliable
     elif cfg.predictor == "adhoc":

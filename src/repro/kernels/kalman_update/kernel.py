@@ -94,4 +94,5 @@ def kalman_fused(b_hat, pi, b_meas_prev, mask,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((w, k), b_hat.dtype)] * 2,
         interpret=interpret,
+        name="kalman_update",
     )(b_hat, pi, b_meas_prev, mask.astype(jnp.int8))

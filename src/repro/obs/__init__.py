@@ -12,10 +12,14 @@ Five planes, all zero-cost when off:
      recording structured ``(tick, kind, tenant, value)`` events for
      controller decisions, fault injections and backoff transitions,
      drained post-run into typed records.
-  3. **Sweep/runtime profiling** (``sim.sweep`` + ``export``): per-chunk
-     wall-clock, compile-vs-execute split and XLA peak-bytes land in the
-     stream manifest and a ``SweepReport``; ``export`` renders a run's
-     ledger or a sweep's chunk timeline as Chrome/Perfetto trace JSON.
+  3. **Sweep/runtime profiling** (``sim.sweep`` + ``spans`` +
+     ``export``): the sweep executor's host spans and per-chunk steps
+     land in the profiler's trace beside the device's ops, and process
+     counters add up trace, compile and callable-cache misses
+     (``spans``); per-chunk wall-clock (the spans' durations),
+     compile-vs-execute split and XLA peak-bytes land in the stream
+     manifest and a ``SweepReport``; ``export`` renders a run's ledger
+     or a sweep's chunk timeline as Chrome/Perfetto trace JSON.
   4. **In-scan anomaly detection** (``detect``): CUSUM/EWMA change-point
      detectors, a chi-square NIS band test over the Kalman innovation
      probes and multi-window SLO burn-rate tracking ride
@@ -50,7 +54,7 @@ so the core control plane can type against ``ObsSpec`` without an import
 cycle.
 """
 
-from . import compare, detect, export, ledger, metrics, probes
+from . import compare, detect, export, ledger, metrics, probes, spans
 from .compare import Divergence, attribution, diff_bench, diff_reports
 from .detect import BURN_NAMES, SIGNAL_NAMES, DetectCarry, DetectSpec
 from .ledger import KIND_NAMES, Ledger, LedgerRecord
@@ -59,6 +63,7 @@ from .probes import (ObsCarry, ObsReport, ObsSpec, TickSignals, drain,
                      hist_percentile, init_carry, update)
 
 __all__ = ["compare", "detect", "export", "ledger", "metrics", "probes",
+           "spans",
            "BURN_NAMES", "SIGNAL_NAMES", "KIND_NAMES", "Divergence",
            "DetectCarry", "DetectSpec", "Ledger", "LedgerRecord",
            "ObsCarry", "ObsReport", "ObsSpec", "TickSignals",

@@ -360,342 +360,356 @@ def make_step(schedule: wl.Schedule | wl.JaxSchedule, cfg: SimConfig, *,
 
     def step(state: SimState, _):
         t = state.t
-        key, k_exec = jax.random.split(state.key)
+        with jax.named_scope("execute"):
+            key, k_exec = jax.random.split(state.key)
         # Observability signal slots — assigned below where the matching
         # plane exists under this config (tenant gate, spot market, chaos
         # engine), None otherwise.  All trace-time.
         obs_rej = obs_pre = obs_kill = None
 
         # --- arrivals ------------------------------------------------------
-        arrive = (sched.t_arrive == t) & sched.valid
-        n_shed_now = 0.0
-        if hardened:
-            # Deadline-aware shedding: during a sustained outage (the
-            # acquisition fail-streak from last tick), refuse arrivals whose
-            # requested deadline is tighter than ``shed_slack`` monitoring
-            # intervals per streak tick — the platform cannot finish them
-            # and admitting them would only convert them into violations.
-            streak_prev = state.faults.fail_streak
-            tight = sched.d_requested < fcfg.shed_slack * streak_prev * cfg.dt
-            shed = (streak_prev >= fcfg.shed_after) & tight
-            n_shed_now = jnp.sum((arrive & shed).astype(jnp.float32))
-            arrive = arrive & ~shed
-        if tcfg is not None:
-            # Admission gate: a tenant already occupying ≥ adm_frac of its
-            # row budget has new arrivals rejected outright (they never
-            # submit, so they neither execute nor count as violations).
-            # The default adm_frac = 1.0 admits everything: an arriving row
-            # is itself inactive, so occupancy is at most max_w - 1.
-            occ = jax.ops.segment_sum(state.work.active.astype(jnp.float32),
-                                      tid, num_segments=tcfg.n)
-            admit = occ < pp.adm_frac * tcfg.max_w
-            # Budget cap: a tenant whose attributed bill has reached its
-            # contracted cap stops admitting work (default: uncapped).
-            spent = state.summ.tenant.cost_u.astype(jnp.float32) / _COST_UNIT
-            admit = admit & (spent < tcfg.budget_vec())
-            if ocfg is not None and (ocfg.fairshare or ocfg.ledger > 0):
-                # Rejected arrivals per tenant, read off the gate before it
-                # filters them (observability: fairshare family + ledger).
-                obs_rej = jax.ops.segment_sum(
-                    (arrive & ~admit[tid]).astype(jnp.float32), tid,
-                    num_segments=tcfg.n)
-            arrive = arrive & admit[tid]
-        work = state.work._replace(
-            active=state.work.active | arrive,
-            m=jnp.where(arrive[:, None], sched.m0, state.work.m),
-            d=jnp.where(arrive, sched.d_requested, state.work.d),
-            t_submit=jnp.where(arrive, t, state.work.t_submit),
-        )
-        c_state = ctrl.reset_rows(state.c, arrive)
+        with jax.named_scope("arrivals"):
+            arrive = (sched.t_arrive == t) & sched.valid
+            n_shed_now = 0.0
+            if hardened:
+                # Deadline-aware shedding: during a sustained outage (the
+                # acquisition fail-streak from last tick), refuse arrivals whose
+                # requested deadline is tighter than ``shed_slack`` monitoring
+                # intervals per streak tick — the platform cannot finish them
+                # and admitting them would only convert them into violations.
+                streak_prev = state.faults.fail_streak
+                tight = sched.d_requested < fcfg.shed_slack * streak_prev * cfg.dt
+                shed = (streak_prev >= fcfg.shed_after) & tight
+                n_shed_now = jnp.sum((arrive & shed).astype(jnp.float32))
+                arrive = arrive & ~shed
+            if tcfg is not None:
+                # Admission gate: a tenant already occupying ≥ adm_frac of its
+                # row budget has new arrivals rejected outright (they never
+                # submit, so they neither execute nor count as violations).
+                # The default adm_frac = 1.0 admits everything: an arriving row
+                # is itself inactive, so occupancy is at most max_w - 1.
+                occ = jax.ops.segment_sum(state.work.active.astype(jnp.float32),
+                                          tid, num_segments=tcfg.n)
+                admit = occ < pp.adm_frac * tcfg.max_w
+                # Budget cap: a tenant whose attributed bill has reached its
+                # contracted cap stops admitting work (default: uncapped).
+                spent = state.summ.tenant.cost_u.astype(jnp.float32) / _COST_UNIT
+                admit = admit & (spent < tcfg.budget_vec())
+                if ocfg is not None and (ocfg.fairshare or ocfg.ledger > 0):
+                    # Rejected arrivals per tenant, read off the gate before it
+                    # filters them (observability: fairshare family + ledger).
+                    obs_rej = jax.ops.segment_sum(
+                        (arrive & ~admit[tid]).astype(jnp.float32), tid,
+                        num_segments=tcfg.n)
+                arrive = arrive & admit[tid]
+            work = state.work._replace(
+                active=state.work.active | arrive,
+                m=jnp.where(arrive[:, None], sched.m0, state.work.m),
+                d=jnp.where(arrive, sched.d_requested, state.work.d),
+                t_submit=jnp.where(arrive, t, state.work.t_submit),
+            )
+            c_state = ctrl.reset_rows(state.c, arrive)
 
         # --- spot market: new clearing prices for [t, t+1) ------------------
         # All Table-V types advance together (correlated log-AR(1)); slots
         # are billed and preempted at *their own type's* price, so one run
         # can hold a mixed-granularity fleet.
-        cluster = state.cluster
-        if use_spot:
-            spot_state = spot_lib.step(state.spot, cfg.spot, cfg.dt,
-                                       ema_alpha=pp.ema_alpha)
-            slot_price = spot_state.prices[cluster.itype]   # (I,)
-            cores = spot_lib.CORES_TABLE[cluster.itype]     # (I,) CUs/slot
-        else:
-            spot_state = state.spot
-            slot_price = None
-            cores = 1.0
+        with jax.named_scope("market"):
+            cluster = state.cluster
+            if use_spot:
+                spot_state = spot_lib.step(state.spot, cfg.spot, cfg.dt,
+                                           ema_alpha=pp.ema_alpha)
+                slot_price = spot_state.prices[cluster.itype]   # (I,)
+                cores = spot_lib.CORES_TABLE[cluster.itype]     # (I,) CUs/slot
+            else:
+                spot_state = state.spot
+                slot_price = None
+                cores = 1.0
 
         # --- chaos engine: this tick's fault draws --------------------------
         # One call on a dedicated PRNG chain, so enabling faults never
         # perturbs the workload, market or execution-noise streams.
-        if fcfg is not None:
-            ftick, fstate = faults_lib.tick(state.faults, fspec, cfg.dt, t)
-            # Stragglers: the slot stays billed at full price but delivers a
-            # fraction of its nominal CU capacity while the episode lasts.
-            exec_cores = cores * ftick.slow
-        else:
-            exec_cores = cores
+        with jax.named_scope("faults"):
+            if fcfg is not None:
+                ftick, fstate = faults_lib.tick(state.faults, fspec, cfg.dt, t)
+                # Stragglers: the slot stays billed at full price but delivers a
+                # fraction of its nominal CU capacity while the episode lasts.
+                exec_cores = cores * ftick.slow
+            else:
+                exec_cores = cores
 
         # --- market preemption: outbid slots are taken the instant the new
         # price clears above their bid — *before* billing advances, so a
         # reclaimed slot never renews a quantum at the very price that
         # killed it ---------------------------------------------------------
-        if use_spot:
-            if ocfg is not None and ocfg.want_preempt:
-                # Per-type preemption counts: the same hit mask
-                # ``billing.preempt`` is about to apply (phase >= BOOTING
-                # and the clearing price strictly above the slot's bid),
-                # bucketed by instance type before the phases are wiped.
-                pb = jnp.broadcast_to(
-                    jnp.asarray(slot_price, jnp.float32), cluster.bid.shape)
-                p_hit = (cluster.phase >= billing_lib.BOOTING) & (
-                    pb > cluster.bid)
-                obs_pre = jax.ops.segment_sum(
-                    p_hit.astype(jnp.float32), cluster.itype,
-                    num_segments=spot_lib.N_TYPES)
-            cluster, _ = billing_lib.preempt(cluster, slot_price)
-        # --- wall clock: boots complete, billing quanta renew ---------------
-        cluster = billing_lib.advance(cluster, cfg.dt, cfg.ctrl.billing,
-                                      price=slot_price)
+        with jax.named_scope("billing"):
+            if use_spot:
+                if ocfg is not None and ocfg.want_preempt:
+                    # Per-type preemption counts: the same hit mask
+                    # ``billing.preempt`` is about to apply (phase >= BOOTING
+                    # and the clearing price strictly above the slot's bid),
+                    # bucketed by instance type before the phases are wiped.
+                    pb = jnp.broadcast_to(
+                        jnp.asarray(slot_price, jnp.float32), cluster.bid.shape)
+                    p_hit = (cluster.phase >= billing_lib.BOOTING) & (
+                        pb > cluster.bid)
+                    obs_pre = jax.ops.segment_sum(
+                        p_hit.astype(jnp.float32), cluster.itype,
+                        num_segments=spot_lib.N_TYPES)
+                cluster, _ = billing_lib.preempt(cluster, slot_price)
+            # --- wall clock: boots complete, billing quanta renew -----------
+            cluster = billing_lib.advance(cluster, cfg.dt, cfg.ctrl.billing,
+                                          price=slot_price)
 
         # --- execute with last instant's rates ------------------------------
-        (new_m, b_meas, meas_mask, exec_time, items_done, util,
-         done_acc) = _execute(
-            work, sched, state.s, cluster, state.done_acc, cfg, k_exec,
-            exec_cores)
-        if fcfg is not None:
-            # Slot hard-kills (storms + Poisson failures) land mid-window:
-            # the killed slots were billed at the last quantum renewal and
-            # burned capacity this window — exactly mid-quantum preemption
-            # billing — but their in-flight work is lost.  The lost items
-            # re-enter the queue exactly once: the rollback is capped at
-            # this window's completions by construction (lost ≤ items_done).
-            act = cluster.phase == billing_lib.ACTIVE
-            slot_cu = act.astype(jnp.float32) * exec_cores
-            tot_cu = jnp.sum(slot_cu)
-            lost_cu = jnp.sum(jnp.where(ftick.kill, slot_cu, 0.0))
-            lost_frac = jnp.where(tot_cu > 0.0,
-                                  lost_cu / jnp.maximum(tot_cu, 1e-9), 0.0)
-            lost = items_done * lost_frac
-            new_m = new_m + lost
-            done_acc = done_acc - jnp.sum(lost, -1)
-            if ocfg is not None and ocfg.want_preempt:
-                # Chaos hard-kills per type, mirroring kill_slots' hit mask.
-                k_hit = (cluster.phase >= billing_lib.BOOTING) & ftick.kill
-                obs_kill = jax.ops.segment_sum(
-                    k_hit.astype(jnp.float32), cluster.itype,
-                    num_segments=spot_lib.N_TYPES)
-            cluster, n_hit = faults_lib.kill_slots(cluster, ftick.kill)
-            fstate = fstate._replace(n_killed=fstate.n_killed + n_hit)
-        done_acc = jnp.where(arrive, 0.0, done_acc)
-        work = work._replace(m=new_m)
-        busy = jnp.where(cluster.phase == billing_lib.ACTIVE, util, 0.0)
-        cluster = cluster._replace(busy_frac=busy)
+        with jax.named_scope("execute"):
+            (new_m, b_meas, meas_mask, exec_time, items_done, util,
+             done_acc) = _execute(
+                work, sched, state.s, cluster, state.done_acc, cfg, k_exec,
+                exec_cores)
+        with jax.named_scope("faults"):
+            if fcfg is not None:
+                # Slot hard-kills (storms + Poisson failures) land mid-window:
+                # the killed slots were billed at the last quantum renewal and
+                # burned capacity this window — exactly mid-quantum preemption
+                # billing — but their in-flight work is lost.  The lost items
+                # re-enter the queue exactly once: the rollback is capped at
+                # this window's completions by construction (lost ≤ items_done).
+                act = cluster.phase == billing_lib.ACTIVE
+                slot_cu = act.astype(jnp.float32) * exec_cores
+                tot_cu = jnp.sum(slot_cu)
+                lost_cu = jnp.sum(jnp.where(ftick.kill, slot_cu, 0.0))
+                lost_frac = jnp.where(tot_cu > 0.0,
+                                      lost_cu / jnp.maximum(tot_cu, 1e-9), 0.0)
+                lost = items_done * lost_frac
+                new_m = new_m + lost
+                done_acc = done_acc - jnp.sum(lost, -1)
+                if ocfg is not None and ocfg.want_preempt:
+                    # Chaos hard-kills per type, mirroring kill_slots' hit mask.
+                    k_hit = (cluster.phase >= billing_lib.BOOTING) & ftick.kill
+                    obs_kill = jax.ops.segment_sum(
+                        k_hit.astype(jnp.float32), cluster.itype,
+                        num_segments=spot_lib.N_TYPES)
+                cluster, n_hit = faults_lib.kill_slots(cluster, ftick.kill)
+                fstate = fstate._replace(n_killed=fstate.n_killed + n_hit)
+        with jax.named_scope("execute"):
+            done_acc = jnp.where(arrive, 0.0, done_acc)
+            work = work._replace(m=new_m)
+            busy = jnp.where(cluster.phase == billing_lib.ACTIVE, util, 0.0)
+            cluster = cluster._replace(busy_frac=busy)
 
-        # --- completions + SLA clock ----------------------------------------
-        done_now = work.active & (jnp.sum(work.m, -1) <= 0.0)
-        work = work._replace(
-            active=work.active & ~done_now,
-            t_done=jnp.where(done_now, t, work.t_done),
-            d=jnp.where(work.active & ~done_now,
-                        work.d - cfg.dt, work.d),
-        )
+            # --- completions + SLA clock ------------------------------------
+            done_now = work.active & (jnp.sum(work.m, -1) <= 0.0)
+            work = work._replace(
+                active=work.active & ~done_now,
+                t_done=jnp.where(done_now, t, work.t_done),
+                d=jnp.where(work.active & ~done_now,
+                            work.d - cfg.dt, work.d),
+            )
 
         # --- telemetry faults: dropouts lose fresh measurements, delays hold
         # them one instant and deliver them stale (eq. 8's lagged form makes
         # a one-tick-stale value well-formed) ---------------------------------
-        meas_dropped = None
-        if fcfg is not None:
-            b_meas, meas_mask, dropped, fstate = faults_lib.filter_telemetry(
-                fstate, ftick, fspec, b_meas, meas_mask, arrive)
-            if hardened:
-                meas_dropped = dropped
+        with jax.named_scope("faults"):
+            meas_dropped = None
+            if fcfg is not None:
+                b_meas, meas_mask, dropped, fstate = faults_lib.filter_telemetry(
+                    fstate, ftick, fspec, b_meas, meas_mask, arrive)
+                if hardened:
+                    meas_dropped = dropped
 
         # --- control --------------------------------------------------------
-        c_state, work, dec = ctrl.step(
-            c_state, work, cluster, b_meas, meas_mask, exec_time, items_done,
-            cfg.ctrl, cores=cores, pp=pp,
-            tenants=(None if tcfg is None else (tid, tcfg.n, base_w)),
-            meas_dropped=meas_dropped, obs=ocfg)
-        if use_spot:
-            rt = spot_state.rt
-            # Dynamic bid policy: the TTC-aware signal is how far the most
-            # behind-schedule active workload has fallen — time fraction of
-            # its deadline used minus work fraction done.  On-track runs
-            # keep the cheap floor bid; runs knocked behind (preemptions,
-            # outages) escalate toward the on-demand cap.
-            frac_time = 1.0 - work.d / jnp.maximum(work.d_requested, 1e-9)
-            frac_done = 1.0 - (jnp.sum(work.m, -1)
-                               / jnp.maximum(jnp.sum(work.m0, -1), 1e-9))
-            behind = jnp.where(work.active, frac_time - frac_done, -jnp.inf)
-            urgency = jnp.clip(pp.ttc_gain * jnp.max(behind), 0.0, 1.0)
-            bids = spot_lib.current_bids(cfg.spot, rt, spot_state, urgency)
-            # Acquisitions pick the cheapest-per-CU currently-available
-            # type of the fleet mix; requests are only fulfilled while the
-            # market clears at or below our bid for that type.  Under the
-            # chaos engine a dried-up type has no capacity at any bid: the
-            # hardened controller hedges by selecting around it, the
-            # unhardened one picks blind and simply fails to start.
-            if fcfg is None:
-                itype_new, can_start = spot_lib.select_type(
-                    spot_state.prices, bids, rt.mix)
-            elif hardened:
-                itype_new, can_start = spot_lib.select_type(
-                    spot_state.prices, bids, rt.mix, avail=ftick.avail)
+        with jax.named_scope("control"):
+            c_state, work, dec = ctrl.step(
+                c_state, work, cluster, b_meas, meas_mask, exec_time, items_done,
+                cfg.ctrl, cores=cores, pp=pp,
+                tenants=(None if tcfg is None else (tid, tcfg.n, base_w)),
+                meas_dropped=meas_dropped, obs=ocfg)
+        with jax.named_scope("acquire"):
+            if use_spot:
+                rt = spot_state.rt
+                # Dynamic bid policy: the TTC-aware signal is how far the most
+                # behind-schedule active workload has fallen — time fraction of
+                # its deadline used minus work fraction done.  On-track runs
+                # keep the cheap floor bid; runs knocked behind (preemptions,
+                # outages) escalate toward the on-demand cap.
+                frac_time = 1.0 - work.d / jnp.maximum(work.d_requested, 1e-9)
+                frac_done = 1.0 - (jnp.sum(work.m, -1)
+                                   / jnp.maximum(jnp.sum(work.m0, -1), 1e-9))
+                behind = jnp.where(work.active, frac_time - frac_done, -jnp.inf)
+                urgency = jnp.clip(pp.ttc_gain * jnp.max(behind), 0.0, 1.0)
+                bids = spot_lib.current_bids(cfg.spot, rt, spot_state, urgency)
+                # Acquisitions pick the cheapest-per-CU currently-available
+                # type of the fleet mix; requests are only fulfilled while the
+                # market clears at or below our bid for that type.  Under the
+                # chaos engine a dried-up type has no capacity at any bid: the
+                # hardened controller hedges by selecting around it, the
+                # unhardened one picks blind and simply fails to start.
+                if fcfg is None:
+                    itype_new, can_start = spot_lib.select_type(
+                        spot_state.prices, bids, rt.mix)
+                elif hardened:
+                    itype_new, can_start = spot_lib.select_type(
+                        spot_state.prices, bids, rt.mix, avail=ftick.avail)
+                else:
+                    itype_new, can_start = spot_lib.select_type(
+                        spot_state.prices, bids, rt.mix)
+                    can_start = can_start & ftick.avail[itype_new]
+                allow = can_start
+                if hardened:
+                    # Bounded-backoff gate: after repeated failed acquisitions
+                    # the controller waits out a jittered exponential delay
+                    # before retrying instead of hammering the market.
+                    trying = state.faults.backoff_left <= 0.0
+                    allow = can_start & trying
+                scale_cores = jnp.where(cluster.phase == billing_lib.OFF,
+                                        spot_lib.CORES_TABLE[itype_new], cores)
+                cluster = billing_lib.scale_to(
+                    cluster, dec.n_target, cfg.ctrl.billing,
+                    price=spot_state.prices[itype_new], bid=bids[itype_new],
+                    itype=itype_new, allow_start=allow, cores=scale_cores)
             else:
-                itype_new, can_start = spot_lib.select_type(
-                    spot_state.prices, bids, rt.mix)
-                can_start = can_start & ftick.avail[itype_new]
-            allow = can_start
-            if hardened:
-                # Bounded-backoff gate: after repeated failed acquisitions
-                # the controller waits out a jittered exponential delay
-                # before retrying instead of hammering the market.
-                trying = state.faults.backoff_left <= 0.0
-                allow = can_start & trying
-            scale_cores = jnp.where(cluster.phase == billing_lib.OFF,
-                                    spot_lib.CORES_TABLE[itype_new], cores)
-            cluster = billing_lib.scale_to(
-                cluster, dec.n_target, cfg.ctrl.billing,
-                price=spot_state.prices[itype_new], bid=bids[itype_new],
-                itype=itype_new, allow_start=allow, cores=scale_cores)
-        else:
-            cluster = billing_lib.scale_to(cluster, dec.n_target,
-                                           cfg.ctrl.billing)
+                cluster = billing_lib.scale_to(cluster, dec.n_target,
+                                               cfg.ctrl.billing)
 
-        # Slots started this tick carry their new type; refresh the CU
-        # weights before reporting control-plane sizes.
-        out_cores = (spot_lib.CORES_TABLE[cluster.itype] if use_spot
-                     else cores)
-        n_committed = billing_lib.committed(cluster, out_cores)
-        if fcfg is not None and use_spot:
-            # Fail-streak / backoff bookkeeping.  The streak counts
-            # *consecutive ticks of unmet demand* — the controller wants to
-            # grow the committed fleet and the market (outbid or dried up)
-            # cannot fulfil it — independent of whether the backoff gate let
-            # this tick's request out.  Counting ticks rather than attempts
-            # matters: the shed gate and the anti-windup clamp key on the
-            # streak as an outage-duration signal, and a streak that only
-            # grew on try-ticks would let the backoff suppress its own
-            # outage detector.
-            fs_prev = state.faults
-            want_grow = dec.n_target > n_committed + 0.5
-            unmet = want_grow & ~can_start
-            streak = jnp.where(unmet, fs_prev.fail_streak + 1.0, 0.0)
-            if hardened:
-                tried = fs_prev.backoff_left <= 0.0
-                delay = aimd_lib.backoff_delay(streak, fcfg.backoff_cap,
-                                               ftick.jitter_u)
-                # A new delay starts only when a request actually went out
-                # and failed; the moment the market observably clears
-                # (``can_start`` — published prices and availability are
-                # free to read) the residual wait is void, so recovery is
-                # never stalled by a backoff scheduled during the outage.
-                backoff_left = jnp.where(
-                    unmet & tried, delay,
-                    jnp.where(can_start, 0.0,
-                              jnp.maximum(fs_prev.backoff_left - 1.0, 0.0)))
-                # Anti-windup: while acquisition keeps failing, hold the
-                # stored AIMD target within one additive step of what is
-                # actually committed, so recovery ramps at the normal AIMD
-                # pace instead of thundering-herd to the windup peak.
-                c_state = c_state._replace(aimd=aimd_lib.anti_windup(
-                    c_state.aimd, n_committed + pp.alpha, streak > 0.0))
+        with jax.named_scope("summary"):
+            # Slots started this tick carry their new type; refresh the CU
+            # weights before reporting control-plane sizes.
+            out_cores = (spot_lib.CORES_TABLE[cluster.itype] if use_spot
+                         else cores)
+            n_committed = billing_lib.committed(cluster, out_cores)
+            if fcfg is not None and use_spot:
+                # Fail-streak / backoff bookkeeping.  The streak counts
+                # *consecutive ticks of unmet demand* — the controller wants to
+                # grow the committed fleet and the market (outbid or dried up)
+                # cannot fulfil it — independent of whether the backoff gate let
+                # this tick's request out.  Counting ticks rather than attempts
+                # matters: the shed gate and the anti-windup clamp key on the
+                # streak as an outage-duration signal, and a streak that only
+                # grew on try-ticks would let the backoff suppress its own
+                # outage detector.
+                fs_prev = state.faults
+                want_grow = dec.n_target > n_committed + 0.5
+                unmet = want_grow & ~can_start
+                streak = jnp.where(unmet, fs_prev.fail_streak + 1.0, 0.0)
+                if hardened:
+                    tried = fs_prev.backoff_left <= 0.0
+                    delay = aimd_lib.backoff_delay(streak, fcfg.backoff_cap,
+                                                   ftick.jitter_u)
+                    # A new delay starts only when a request actually went out
+                    # and failed; the moment the market observably clears
+                    # (``can_start`` — published prices and availability are
+                    # free to read) the residual wait is void, so recovery is
+                    # never stalled by a backoff scheduled during the outage.
+                    backoff_left = jnp.where(
+                        unmet & tried, delay,
+                        jnp.where(can_start, 0.0,
+                                  jnp.maximum(fs_prev.backoff_left - 1.0, 0.0)))
+                    # Anti-windup: while acquisition keeps failing, hold the
+                    # stored AIMD target within one additive step of what is
+                    # actually committed, so recovery ramps at the normal AIMD
+                    # pace instead of thundering-herd to the windup peak.
+                    c_state = c_state._replace(aimd=aimd_lib.anti_windup(
+                        c_state.aimd, n_committed + pp.alpha, streak > 0.0))
+                else:
+                    backoff_left = fs_prev.backoff_left
+                fstate = fstate._replace(
+                    fail_streak=streak, backoff_left=backoff_left,
+                    n_shed=fstate.n_shed + n_shed_now)
+            elif fcfg is not None:
+                fstate = fstate._replace(n_shed=fstate.n_shed + n_shed_now)
             else:
-                backoff_left = fs_prev.backoff_left
-            fstate = fstate._replace(
-                fail_streak=streak, backoff_left=backoff_left,
-                n_shed=fstate.n_shed + n_shed_now)
-        elif fcfg is not None:
-            fstate = fstate._replace(n_shed=fstate.n_shed + n_shed_now)
-        else:
-            fstate = None
-        spot_price = (spot_state.price if use_spot
-                      else jnp.asarray(cfg.ctrl.billing.price_per_quantum,
-                                       jnp.float32))
+                fstate = None
+            spot_price = (spot_state.price if use_spot
+                          else jnp.asarray(cfg.ctrl.billing.price_per_quantum,
+                                           jnp.float32))
 
-        # Summary registers (see SummaryCarry).  The cost register fires on
-        # the tick *after* a completion — the trace index
-        # ``cost_at_completion`` reads — and is overwritten whenever a later
-        # completion moves that endpoint, so its final value is
-        # ``cum_cost[max(t_done) + 1]``.  The fire flag re-uses this tick's
-        # ``done_now`` instead of re-deriving the endpoint from a W-wide
-        # ``max(t_done)`` every tick (summary-mode hot-loop cost).
-        summ = SummaryCarry(
-            max_committed=jnp.maximum(state.summ.max_committed, n_committed),
-            price_sum=state.summ.price_sum + spot_price,
-            price_max=jnp.maximum(state.summ.price_max, spot_price),
-            cost_at_done=jnp.where(state.summ.fire, cluster.cum_cost,
-                                   state.summ.cost_at_done),
-            fire=jnp.any(done_now),
-            tenant=(None if tcfg is None else _attribute(
-                state.summ.tenant, cluster.cum_cost, exec_time, sched.valid,
-                tid, base_w, tcfg.n)),
-        )
+            # Summary registers (see SummaryCarry).  The cost register fires on
+            # the tick *after* a completion — the trace index
+            # ``cost_at_completion`` reads — and is overwritten whenever a later
+            # completion moves that endpoint, so its final value is
+            # ``cum_cost[max(t_done) + 1]``.  The fire flag re-uses this tick's
+            # ``done_now`` instead of re-deriving the endpoint from a W-wide
+            # ``max(t_done)`` every tick (summary-mode hot-loop cost).
+            summ = SummaryCarry(
+                max_committed=jnp.maximum(state.summ.max_committed, n_committed),
+                price_sum=state.summ.price_sum + spot_price,
+                price_max=jnp.maximum(state.summ.price_max, spot_price),
+                cost_at_done=jnp.where(state.summ.fire, cluster.cum_cost,
+                                       state.summ.cost_at_done),
+                fire=jnp.any(done_now),
+                tenant=(None if tcfg is None else _attribute(
+                    state.summ.tenant, cluster.cum_cost, exec_time, sched.valid,
+                    tid, base_w, tcfg.n)),
+            )
 
         # --- observability: accumulate this tick's probe registers ----------
         # Strictly read-only — every signal is a value computed above, no
         # PRNG is consumed, nothing flows back into the simulation, so an
         # obs=None config compiles this block away entirely.
-        obs_c = state.obs
-        if ocfg is not None:
-            pr = dec.probe
-            obs_viol = obs_cost = None
-            if ocfg.detect is not None:
-                # Detector inputs, still read-only: TTC violations judged
-                # at completion time (the same lateness rule as
-                # ``violation_rows``; never-finished work is only judged
-                # at the horizon) and this tick's billed spend.
-                ticks_allowed = jnp.ceil(sched.d_requested / cfg.dt)
-                late = (t - work.t_submit) - ticks_allowed
-                obs_viol = jnp.sum(
-                    (done_now & sched.valid & (late > 1))
-                    .astype(jnp.float32))
-                obs_cost = cluster.cum_cost - state.cluster.cum_cost
-            sig = obs_lib.TickSignals(
-                aimd_incr=pr.aimd_incr,
-                water_scale=pr.water_scale,
-                kalman=pr.kalman,
-                n_target=dec.n_target,
-                preempt_by_type=obs_pre,
-                kill_by_type=obs_kill,
-                adm_rejects=obs_rej,
-                queue_depth=jnp.sum(work.active.astype(jnp.float32)),
-                fail_streak=(fstate.fail_streak
-                             if (fcfg is not None and use_spot) else None),
-                n_shed=(n_shed_now if hardened else None),
-                spot_price=spot_price,
-                viol_now=obs_viol,
-                n_committed=n_committed,
-                n_unavail=(jnp.sum((~ftick.avail).astype(jnp.float32))
-                           if fcfg is not None else None),
-                cost_delta=obs_cost)
-            obs_c = obs_lib.update(state.obs, ocfg, t, sig,
-                                   q_cap=sched.t_arrive.shape[0])
+        with jax.named_scope("obs"):
+            obs_c = state.obs
+            if ocfg is not None:
+                pr = dec.probe
+                obs_viol = obs_cost = None
+                if ocfg.detect is not None:
+                    # Detector inputs, still read-only: TTC violations judged
+                    # at completion time (the same lateness rule as
+                    # ``violation_rows``; never-finished work is only judged
+                    # at the horizon) and this tick's billed spend.
+                    ticks_allowed = jnp.ceil(sched.d_requested / cfg.dt)
+                    late = (t - work.t_submit) - ticks_allowed
+                    obs_viol = jnp.sum(
+                        (done_now & sched.valid & (late > 1))
+                        .astype(jnp.float32))
+                    obs_cost = cluster.cum_cost - state.cluster.cum_cost
+                sig = obs_lib.TickSignals(
+                    aimd_incr=pr.aimd_incr,
+                    water_scale=pr.water_scale,
+                    kalman=pr.kalman,
+                    n_target=dec.n_target,
+                    preempt_by_type=obs_pre,
+                    kill_by_type=obs_kill,
+                    adm_rejects=obs_rej,
+                    queue_depth=jnp.sum(work.active.astype(jnp.float32)),
+                    fail_streak=(fstate.fail_streak
+                                 if (fcfg is not None and use_spot) else None),
+                    n_shed=(n_shed_now if hardened else None),
+                    spot_price=spot_price,
+                    viol_now=obs_viol,
+                    n_committed=n_committed,
+                    n_unavail=(jnp.sum((~ftick.avail).astype(jnp.float32))
+                               if fcfg is not None else None),
+                    cost_delta=obs_cost)
+                obs_c = obs_lib.update(state.obs, ocfg, t, sig,
+                                       q_cap=sched.t_arrive.shape[0])
 
-        new_state = SimState(c=c_state, work=work, cluster=cluster, s=dec.s,
-                             done_acc=done_acc, key=key, t=t + 1,
-                             spot=spot_state, summ=summ, faults=fstate,
-                             obs=obs_c)
-        if not trace:
-            return new_state, None
-        out = dict(
-            cum_cost=cluster.cum_cost,
-            n_usable=billing_lib.usable(cluster, out_cores),
-            n_committed=n_committed,
-            n_star=dec.n_star,
-            n_target=dec.n_target,
-            util=util,
-            b_hat=dec.b_hat,
-            b_meas=b_meas,
-            reliable=dec.reliable,
-            confirmed=work.confirmed,
-            active=work.active,
-            remaining=jnp.sum(work.m, -1),
-            spot_price=spot_price,
-            spot_bid=(bids[spot_state.rt.itype] if use_spot
-                      else jnp.asarray(jnp.inf, jnp.float32)),
-            n_preempted=cluster.n_preempt,
-        )
-        return new_state, out
+        with jax.named_scope("summary"):
+            new_state = SimState(c=c_state, work=work, cluster=cluster, s=dec.s,
+                                 done_acc=done_acc, key=key, t=t + 1,
+                                 spot=spot_state, summ=summ, faults=fstate,
+                                 obs=obs_c)
+            if not trace:
+                return new_state, None
+            out = dict(
+                cum_cost=cluster.cum_cost,
+                n_usable=billing_lib.usable(cluster, out_cores),
+                n_committed=n_committed,
+                n_star=dec.n_star,
+                n_target=dec.n_target,
+                util=util,
+                b_hat=dec.b_hat,
+                b_meas=b_meas,
+                reliable=dec.reliable,
+                confirmed=work.confirmed,
+                active=work.active,
+                remaining=jnp.sum(work.m, -1),
+                spot_price=spot_price,
+                spot_bid=(bids[spot_state.rt.itype] if use_spot
+                          else jnp.asarray(jnp.inf, jnp.float32)),
+                n_preempted=cluster.n_preempt,
+            )
+            return new_state, out
 
     return step
 

@@ -73,7 +73,6 @@ import hashlib
 import json
 import os
 import shutil
-import time
 import warnings
 from typing import NamedTuple, Sequence
 
@@ -85,6 +84,7 @@ from jax.sharding import Mesh, PartitionSpec
 from ..checkpoint import checkpointer
 from ..core.types import PolicyParams
 from ..launch import mesh as mesh_lib
+from ..obs import spans
 from . import faults as faults_lib
 from . import runner, spot
 from . import scenarios as scen_lib
@@ -343,10 +343,11 @@ class SweepSpec:
     # Runtime profiling (``repro.obs`` plane iii): per-chunk wall clock
     # with the compile vs execute split (AOT ``lower().compile()`` on the
     # first chunk), XLA peak-bytes estimate, and — when streaming — the
-    # chunk-write time.  ``sweep`` then returns a :class:`SweepReport`
+    # chunk-write time, each the duration of the sweep's own host spans
+    # (``obs.spans``).  ``sweep`` then returns a :class:`SweepReport`
     # wrapping the unchanged result; the stream manifest gains a
-    # ``"profile"`` record.  Off by default: an unprofiled sweep takes the
-    # exact pre-profiling code path (no timing calls around the dispatch).
+    # ``"profile"`` record.  Off by default: an unprofiled sweep compiles
+    # through the jit cache, with no AOT compile of its own.
     profile: bool = dataclasses.field(default=False, kw_only=True)
 
     def __post_init__(self):
@@ -564,6 +565,7 @@ def _sweep_callable(workload, cfg: runner.SimConfig,
     fn = runner._JIT_CACHE.get(key)
     if fn is not None:
         return fn
+    spans.count_callable_miss()
     in_axes = (0, 0, 0, 0, 0, 0, None, None) + ((0,) if chaos else ())
     batched = jax.vmap(pt, in_axes=in_axes)
     if mesh is not None:
@@ -663,11 +665,16 @@ def _take_rows(host_tree, rows: int, chunk: int, where: str):
 class ChunkProfile:
     """One micro-batch's runtime profile (``SweepSpec.profile=True``).
 
-    ``compile_s`` is non-zero only on the chunk that triggered the AOT
-    compile (all chunks share one padded shape, hence one executable);
-    ``write_s`` only on streamed sweeps (the atomic chunk-file commit);
-    ``resumed`` marks chunks a streamed sweep found already committed —
-    their timings are zero because no work was re-done.
+    Each time is the duration of the chunk's host spans (``obs.spans``),
+    so a profiled sweep run under ``jax.profiler.trace`` shows the same
+    spans on the device trace's clock: ``compile_s`` is ``sweep.compile``,
+    non-zero only on the chunk that triggered the AOT compile (all chunks
+    share one padded shape, hence one executable); ``execute_s`` is
+    ``sweep.launch`` plus ``sweep.to_host`` (the launch, the run and the
+    summaries brought to the host); ``write_s`` is ``sweep.write``, only
+    on streamed sweeps (the atomic chunk-file commit); ``resumed`` marks
+    chunks a streamed sweep found already committed — their timings are
+    zero because no work was re-done.
     """
 
     chunk: int
@@ -690,7 +697,7 @@ class SweepReport:
 
     result: object
     chunks: list          # [ChunkProfile] in chunk order
-    total_s: float        # executor wall clock, compile + dispatch + I/O
+    total_s: float        # the ``sweep`` span: checks, compile, dispatch, I/O
 
     def write_trace(self, path) -> None:
         """Render the chunk timeline as Chrome/Perfetto trace-event JSON
@@ -855,103 +862,149 @@ def sweep(spec: SweepSpec, cfg: runner.SimConfig):
     Execution options live on the spec (see :class:`SweepSpec`); an
     interrupted streamed sweep resumes from its last committed chunk when
     re-invoked with the same spec and ``stream_dir``.
+
+    Each phase of the call is a host span of ``obs.spans`` (``sweep`` and
+    the ``sweep.*`` spans inside it, one ``sweep_chunk`` step per chunk),
+    so a profiler trace names what the device waits on.
     """
-    workload = spec.workload
-    is_set = isinstance(workload, scen_lib.ScenarioSet)
-    is_tenants = _is_tenant_set(workload)
-    check_cfg = workload.sim_config(cfg) if is_tenants else cfg
-    _check_axes(check_cfg, spec.axes, workload)
-    pp = (runner.default_params(check_cfg) if spec.params is None
-          else spec.params)
-    # The dummy stands in for the (unused) schedule argument when the
-    # scenario set / tenant set generates schedules internally.
-    sched = (jnp.zeros((0,)) if (is_set or is_tenants)
-             else wl.as_jax_schedule(workload))
-    axes = spec.axes
-    b = spec.n_points
-    fspec = _norm_faults(spec, check_cfg, b)
+    with spans.span("sweep", points=spec.n_points) as whole:
+        result, profiles = _sweep(spec, cfg)
+    spans.sweep_done()
+    if not spec.profile:
+        return result
+    return SweepReport(result=result, chunks=profiles, total_s=whole.seconds)
 
-    avail = len(jax.devices())
-    if spec.mesh is not None:
-        n_dev = min(spec.mesh.size, b)
-        mesh = spec.mesh if n_dev == spec.mesh.size else None
-    else:
-        n_dev = avail if spec.devices is None else min(int(spec.devices),
-                                                       avail)
-        n_dev = min(n_dev, b)
-        mesh = None
-    if n_dev > 1 and mesh is None:
-        mesh = mesh_lib.make_sweep_mesh(n_dev)
 
-    ftail = () if fspec is None else (fspec,)
-    if (spec.chunk_size is None and n_dev == 1 and spec.stream_dir is None
-            and not spec.profile):
-        return _sweep_callable(workload, cfg, None)(*axes, sched, pp, *ftail)
+def _sweep(spec: SweepSpec, cfg: runner.SimConfig):
+    """``sweep``'s work: the result, and with ``spec.profile`` one
+    :class:`ChunkProfile` per chunk (else ``None``)."""
+    with spans.span("sweep.prepare"):
+        workload = spec.workload
+        is_set = isinstance(workload, scen_lib.ScenarioSet)
+        is_tenants = _is_tenant_set(workload)
+        check_cfg = workload.sim_config(cfg) if is_tenants else cfg
+        _check_axes(check_cfg, spec.axes, workload)
+        pp = (runner.default_params(check_cfg) if spec.params is None
+              else spec.params)
+        # The dummy stands in for the (unused) schedule argument when the
+        # scenario set / tenant set generates schedules internally.
+        sched = (jnp.zeros((0,)) if (is_set or is_tenants)
+                 else wl.as_jax_schedule(workload))
+        axes = spec.axes
+        b = spec.n_points
+        fspec = _norm_faults(spec, check_cfg, b)
 
-    chunk = b if spec.chunk_size is None else min(int(spec.chunk_size), b)
-    # Each compiled chunk covers a device multiple of runs (the explicit
-    # padding policy: the grid never has to divide the device count).
-    chunk = -(-chunk // n_dev) * n_dev
-    donating = jax.default_backend() != "cpu"
-    fn = _sweep_callable(workload, cfg, mesh, donate=True)
-    n_chunks = -(-b // chunk)
-    t_sweep = time.perf_counter()
+        avail = len(jax.devices())
+        if spec.mesh is not None:
+            n_dev = min(spec.mesh.size, b)
+            mesh = spec.mesh if n_dev == spec.mesh.size else None
+        else:
+            n_dev = avail if spec.devices is None else min(int(spec.devices),
+                                                           avail)
+            n_dev = min(n_dev, b)
+            mesh = None
+        if n_dev > 1 and mesh is None:
+            mesh = mesh_lib.make_sweep_mesh(n_dev)
 
+        ftail = () if fspec is None else (fspec,)
+        fast = (spec.chunk_size is None and n_dev == 1
+                and spec.stream_dir is None and not spec.profile)
+        chunk = b if spec.chunk_size is None else min(int(spec.chunk_size),
+                                                      b)
+        # Each compiled chunk covers a device multiple of runs (the
+        # explicit padding policy: the grid never has to divide the device
+        # count).
+        chunk = -(-chunk // n_dev) * n_dev
+        donating = jax.default_backend() != "cpu"
+        n_chunks = -(-b // chunk)
+
+    if fast:
+        with spans.span("sweep.lookup"):
+            fn = _sweep_callable(workload, cfg, None)
+        with spans.span("sweep.launch", rows=b, padded=b):
+            return fn(*axes, sched, pp, *ftail), None
+
+    with spans.span("sweep.lookup"):
+        fn = _sweep_callable(workload, cfg, mesh, donate=True)
     if spec.stream_dir is not None:
-        stream, profiles = _run_streamed(
+        return _run_streamed(
             fn, axes, sched, pp, b, chunk, n_chunks,
             os.fspath(spec.stream_dir), spec.resume,
             donating, workload, check_cfg, fspec=fspec,
             profile=spec.profile)
-        if not spec.profile:
-            return stream
-        return SweepReport(result=stream, chunks=profiles,
-                           total_s=time.perf_counter() - t_sweep)
 
     outs = []
-    profiles: list[ChunkProfile] = []
-    compiled = None
-    peak = None
+    profiles: list[ChunkProfile] | None = [] if spec.profile else None
+    chunker = _ChunkRunner(fn, axes, fspec, sched, pp, chunk, donating,
+                           spec.profile)
     for i in range(n_chunks):
         lo = i * chunk
         hi = min(lo + chunk, b)
-        part = _pad_axes(_slice_axes(axes, lo, hi, copy=donating), chunk)
-        fpart = (() if fspec is None else
-                 (_pad_fspec(_slice_fspec(fspec, lo, hi), hi - lo, chunk),))
+        with spans.chunk(i):
+            host, times = chunker.run(lo, hi, "the summary")
+        outs.append(host)
         if spec.profile:
-            # Compile-vs-execute split via the AOT path: every chunk is
-            # padded to one shape, so the first chunk's executable serves
-            # them all and only it pays (and reports) the compile.
-            compile_s = 0.0
-            if compiled is None:
-                t0 = time.perf_counter()
-                compiled = fn.lower(*part, sched, pp, *fpart).compile()
-                compile_s = time.perf_counter() - t0
-                peak = peak_bytes(compiled)
-            t0 = time.perf_counter()
-            res = jax.block_until_ready(compiled(*part, sched, pp, *fpart))
-            profiles.append(ChunkProfile(
-                chunk=i, rows=hi - lo, compile_s=compile_s,
-                execute_s=time.perf_counter() - t0, peak_bytes=peak))
-        else:
-            res = fn(*part, sched, pp, *fpart)
+            profiles.append(ChunkProfile(chunk=i, rows=hi - lo,
+                                         peak_bytes=chunker.peak, **times))
+    with spans.span("sweep.assemble", rows=b):
+        cat = (outs[0] if len(outs) == 1 else
+               jax.tree.map(lambda *xs: np.concatenate(xs), *outs))
+        for leaf in jax.tree.leaves(cat):
+            if leaf.shape[0] != b:
+                raise AssertionError(
+                    f"chunked sweep produced {leaf.shape[0]} rows for {b} "
+                    "grid points — padded points would leak into the "
+                    "summary")
+        return jax.tree.map(jnp.asarray, cat), profiles
+
+
+class _ChunkRunner:
+    """Runs one chunk of a chunked sweep at a time, each phase in its own
+    host span: the axes sliced and padded to the chunk shape, the launch,
+    the summaries brought to the host and cut to the chunk's live rows.
+
+    A profiled sweep takes the compile-vs-execute split via the AOT path:
+    every chunk is padded to one shape, so the first chunk's executable
+    serves them all and only it pays (and reports) the compile; ``peak``
+    is then XLA's peak-bytes estimate of that executable."""
+
+    def __init__(self, fn, axes, fspec, sched, pp, chunk, donating,
+                 profile):
+        self.fn, self.axes, self.fspec = fn, axes, fspec
+        self.sched, self.pp, self.chunk = sched, pp, chunk
+        self.donating, self.profile = donating, profile
+        self.compiled = None
+        self.peak = None
+
+    def run(self, lo: int, hi: int, where: str):
+        """Rows ``[lo, hi)``: their summaries on the host, and the
+        ``ChunkProfile`` times they took (each a span's duration)."""
+        chunk = self.chunk
+        with spans.span("sweep.slice_pad", rows=hi - lo, padded=chunk):
+            part = _pad_axes(_slice_axes(self.axes, lo, hi,
+                                         copy=self.donating), chunk)
+            fpart = (() if self.fspec is None else
+                     (_pad_fspec(_slice_fspec(self.fspec, lo, hi), hi - lo,
+                                 chunk),))
+        args = (*part, self.sched, self.pp, *fpart)
+        compile_s = 0.0
+        if self.profile and self.compiled is None:
+            with spans.span("sweep.compile") as compiling:
+                self.compiled = self.fn.lower(*args).compile()
+            compile_s = compiling.seconds
+            self.peak = peak_bytes(self.compiled)
+        run = self.fn if self.compiled is None else self.compiled
+        with spans.span("sweep.launch", rows=hi - lo,
+                        padded=chunk) as launch:
+            res = run(*args)
         # Off-device before the next chunk so live bytes stay O(chunk);
         # summaries are plain pytrees of dense arrays, so the transfer is
         # reformat-free.
-        host = jax.tree.map(np.asarray, res)
-        outs.append(_take_rows(host, hi - lo, chunk, "the summary"))
-    cat = (outs[0] if len(outs) == 1 else
-           jax.tree.map(lambda *xs: np.concatenate(xs), *outs))
-    for leaf in jax.tree.leaves(cat):
-        if leaf.shape[0] != b:
-            raise AssertionError(
-                f"chunked sweep produced {leaf.shape[0]} rows for {b} grid "
-                "points — padded points would leak into the summary")
-    result = jax.tree.map(jnp.asarray, cat)
-    if not spec.profile:
-        return result
-    return SweepReport(result=result, chunks=profiles,
-                       total_s=time.perf_counter() - t_sweep)
+        with spans.span("sweep.to_host", rows=hi - lo) as to_host:
+            host = _take_rows(jax.tree.map(np.asarray, res), hi - lo, chunk,
+                              where)
+        return host, {"compile_s": compile_s,
+                      "execute_s": launch.seconds + to_host.seconds}
 
 
 def _run_streamed(fn, axes: SweepAxes, sched, pp, b: int, chunk: int,
@@ -971,22 +1024,25 @@ def _run_streamed(fn, axes: SweepAxes, sched, pp, b: int, chunk: int,
     per-file sha256 digests in their chunk manifests; a corrupted or
     truncated chunk is silently recomputed instead of resumed.
     """
-    cfg_token = repr(runner.strip_tuned(check_cfg))
-    digest = _spec_digest(axes, b, chunk, cfg_token,
-                          _workload_token(workload), pp, fspec=fspec)
-    manifest = _stream_init(directory, digest, b, chunk, n_chunks, resume)
-    done = {s for s in checkpointer.committed_steps(directory)
-            if checkpointer.verify(directory, s)}
+    with spans.span("sweep.prepare"):
+        cfg_token = repr(runner.strip_tuned(check_cfg))
+        digest = _spec_digest(axes, b, chunk, cfg_token,
+                              _workload_token(workload), pp, fspec=fspec)
+        manifest = _stream_init(directory, digest, b, chunk, n_chunks,
+                                resume)
+        done = {s for s in checkpointer.committed_steps(directory)
+                if checkpointer.verify(directory, s)}
 
-    part0 = _pad_axes(_slice_axes(axes, 0, min(chunk, b), copy=False), chunk)
-    ftail0 = (() if fspec is None else
-              (_pad_fspec(_slice_fspec(fspec, 0, min(chunk, b)),
-                          min(chunk, b), chunk),))
-    struct = jax.eval_shape(fn, *part0, sched, pp, *ftail0)
+        part0 = _pad_axes(_slice_axes(axes, 0, min(chunk, b), copy=False),
+                          chunk)
+        ftail0 = (() if fspec is None else
+                  (_pad_fspec(_slice_fspec(fspec, 0, min(chunk, b)),
+                              min(chunk, b), chunk),))
+        struct = jax.eval_shape(fn, *part0, sched, pp, *ftail0)
 
     profiles: list[ChunkProfile] | None = [] if profile else None
-    compiled = None
-    peak = None
+    chunker = _ChunkRunner(fn, axes, fspec, sched, pp, chunk, donating,
+                           profile)
     for i in range(n_chunks):
         rows = min(chunk, b - i * chunk)
         if i in done:
@@ -998,47 +1054,32 @@ def _run_streamed(fn, axes: SweepAxes, sched, pp, b: int, chunk: int,
             continue
         lo = i * chunk
         hi = min(lo + chunk, b)
-        part = _pad_axes(_slice_axes(axes, lo, hi, copy=donating), chunk)
-        fpart = (() if fspec is None else
-                 (_pad_fspec(_slice_fspec(fspec, lo, hi), hi - lo, chunk),))
-        if profile:
-            compile_s = 0.0
-            if compiled is None:
-                t0 = time.perf_counter()
-                compiled = fn.lower(*part, sched, pp, *fpart).compile()
-                compile_s = time.perf_counter() - t0
-                peak = peak_bytes(compiled)
-            t0 = time.perf_counter()
-            res = jax.block_until_ready(compiled(*part, sched, pp, *fpart))
-            execute_s = time.perf_counter() - t0
-        else:
-            res = fn(*part, sched, pp, *fpart)
-        host = jax.tree.map(np.asarray, res)
-        host = _take_rows(host, hi - lo, chunk, "a written chunk file")
-        t0 = time.perf_counter()
-        checkpointer.save(directory, i, host)
+        with spans.chunk(i):
+            host, times = chunker.run(lo, hi, "a written chunk file")
+            with spans.span("sweep.write", rows=hi - lo) as write:
+                checkpointer.save(directory, i, host)
         if profile:
             profiles.append(ChunkProfile(
-                chunk=i, rows=hi - lo, compile_s=compile_s,
-                execute_s=execute_s, write_s=time.perf_counter() - t0,
-                peak_bytes=peak))
-        del res, host   # live bytes stay O(chunk) no matter the grid
+                chunk=i, rows=hi - lo, write_s=write.seconds,
+                peak_bytes=chunker.peak, **times))
+        del host   # live bytes stay O(chunk) no matter the grid
 
-    if profile:
-        # Persist the run's profile next to the sweep identity.  The
-        # manifest comparison on resume strips this key: profiling a
-        # sweep must never un-resume its own stream_dir.
-        manifest = dict(manifest,
-                        profile=[dataclasses.asdict(p) for p in profiles])
-        path = os.path.join(directory, _MANIFEST)
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(manifest, f)
-        os.replace(tmp, path)
+    with spans.span("sweep.assemble", rows=b):
+        if profile:
+            # Persist the run's profile next to the sweep identity.  The
+            # manifest comparison on resume strips this key: profiling a
+            # sweep must never un-resume its own stream_dir.
+            manifest = dict(manifest,
+                            profile=[dataclasses.asdict(p) for p in profiles])
+            path = os.path.join(directory, _MANIFEST)
+            tmp = path + ".tmp"
+            with open(tmp, "w") as f:
+                json.dump(manifest, f)
+            os.replace(tmp, path)
 
-    stream = SweepStream(directory=directory, n_points=b, chunk_size=chunk,
-                         n_chunks=n_chunks, manifest=manifest,
-                         _struct=struct)
+        stream = SweepStream(directory=directory, n_points=b,
+                             chunk_size=chunk, n_chunks=n_chunks,
+                             manifest=manifest, _struct=struct)
     return stream, profiles
 
 

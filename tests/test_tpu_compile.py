@@ -16,6 +16,7 @@ import dataclasses
 import functools
 import os
 import pathlib
+import re
 import sys
 
 import jax
@@ -90,7 +91,10 @@ def test_kalman_kernel_compiles_for_v5e(one_chip, chip_compile, rows, cols):
     fn = functools.partial(kalman_kernel.kalman_fused, sigma_z2=0.5,
                            sigma_v2=0.5, interpret=False)
     compiled = jax.jit(fn).lower(f32, f32, f32, mask).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The kernel's stable name, which a device trace's op names carry.
+    assert re.search(r"%kalman_update[\w.]* = .*tpu_custom_call", text)
     print(compiled.memory_analysis())
 
 
@@ -110,5 +114,7 @@ def test_sweep_chunk_with_kalman_kernel_compiles_for_v5e(
                      runner.default_params(cfg)), one_chip)
     fn = sweep_lib._sweep_callable(SCHEDULE, cfg, None)
     compiled = fn.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%kalman_update[\w.]* = .*tpu_custom_call", text)
     print(compiled.memory_analysis())
