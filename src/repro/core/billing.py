@@ -39,6 +39,7 @@ price is simply not fulfilled.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from .types import BillingParams, ClusterState
 
@@ -252,13 +253,20 @@ def _take(key: jnp.ndarray, weights: jnp.ndarray, budget: jnp.ndarray,
     ``rank ≤ budget``.  Callers mask the result: slots keyed ``inf`` sort
     last but can still be marked once the budget exceeds the eligible
     weight.
+
+    The order comes from one multi-operand ``lax.sort`` keyed on
+    ``(key, slot index)``, with the weights carried along: the comparator
+    ``jnp.argsort`` uses (``-0.0`` ties ``0.0``), ties broken by slot as
+    a stable sort does. A second sort keyed on the permutation puts the
+    marks back in slot order. No index gathers or scatters: under
+    ``vmap`` the TPU runs per-row-indexed ones as scalar loops.
     """
-    pool = key.shape[0]
-    order = jnp.argsort(key)
-    w_sorted = weights[order]
+    iota = lax.iota(jnp.int32, key.shape[0])
+    _, perm, w_sorted = lax.sort((key, iota, weights), num_keys=2)
     incl = jnp.cumsum(w_sorted)
     taken = (incl - w_sorted) < budget if cover else incl <= budget
-    return jnp.zeros((pool,), bool).at[order].set(taken)
+    _, out = lax.sort((perm, taken), num_keys=1)
+    return out
 
 
 def lower_bound_cost(total_cus: jnp.ndarray,

@@ -2,6 +2,7 @@
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +10,7 @@ from hypothesis_compat import given, settings, strategies as st
 
 from repro.core import billing
 from repro.core.types import BillingParams
+from repro.sim.spot import CORES_TABLE
 
 B = BillingParams(boot_delay=120.0, terminate="boundary")
 BI = dataclasses.replace(B, terminate="immediate")
@@ -111,3 +113,71 @@ def test_lifecycle_invariants(targets, mode):
         assert (np.asarray(c.a)[live] > -60.0).all()
         assert float(billing.committed(c)) == pytest.approx(
             min(float(t), 16), abs=0)
+
+
+def _take_by_indexing(key, weights, budget, cover):
+    """The argsort / gather / scatter formulation ``_take`` replaced."""
+    order = jnp.argsort(key)
+    w_sorted = weights[order]
+    incl = jnp.cumsum(w_sorted)
+    taken = (incl - w_sorted) < budget if cover else incl <= budget
+    return jnp.zeros(key.shape, bool).at[order].set(taken)
+
+
+def _take_batch(seed, rows, pool):
+    """Keys with ties, ``inf``, signed zeros and negatives; spot weights."""
+    rng = np.random.default_rng(seed)
+    specials = np.asarray([np.inf, -0.0, 0.0, -1.0, 2.0, -3600.0],
+                          np.float32)
+    key = rng.integers(-4, 5, (rows, pool)).astype(np.float32) * 900.0
+    pick = rng.random((rows, pool))
+    key = np.where(pick < 0.4, specials[rng.integers(0, 6, (rows, pool))],
+                   key).astype(np.float32)
+    weights = np.asarray(CORES_TABLE)[rng.integers(0, len(CORES_TABLE),
+                                                   (rows, pool))]
+    total = weights.sum(axis=1)
+    budget = np.round(rng.random(rows) * total * 1.2).astype(np.float32)
+    budget[:3] = [0.0, total[1], total[2] + 1.0]
+    return jnp.asarray(key), jnp.asarray(weights), jnp.asarray(budget)
+
+
+@pytest.mark.parametrize("cover", [True, False])
+@pytest.mark.parametrize("pool", [1, 4, 160])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_take_matches_the_indexing_formulation(seed, pool, cover):
+    """``_take``'s two sorts give the argsort/gather/scatter marks bit for
+    bit under ``vmap``."""
+    key, weights, budget = _take_batch(seed, 64, pool)
+    got = jax.vmap(lambda k, w, b: billing._take(k, w, b, cover))(
+        key, weights, budget)
+    want = jax.vmap(lambda k, w, b: _take_by_indexing(k, w, b, cover))(
+        key, weights, budget)
+    assert got.dtype == want.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_take_ties_signed_zeros_and_breaks_ties_by_slot():
+    """A draining slot at ``a = 0`` keys ``-0.0``: the sort ties it with a
+    ``0.0`` key, as ``jnp.argsort`` does, and equal keys are taken in
+    slot order."""
+    key = jnp.asarray([0.0, -0.0, 5.0, 5.0, jnp.inf], jnp.float32)
+    ones = jnp.ones((5,), jnp.float32)
+    marks = billing._take(key, ones, jnp.asarray(1.0))
+    assert np.asarray(marks).tolist() == [True, False, False, False, False]
+    marks = billing._take(key[::-1], ones, jnp.asarray(1.0))
+    assert np.asarray(marks).tolist() == [False, False, False, True, False]
+    marks = billing._take(key, ones, jnp.asarray(3.0))
+    assert np.asarray(marks).tolist() == [True, True, True, False, False]
+
+
+@pytest.mark.parametrize("cover", [True, False])
+def test_take_lowers_without_gather_or_scatter(cover):
+    """The slot ordering is sorts only: a vmapped per-row gather or
+    scatter runs as a scalar loop on the TPU."""
+    key, weights, budget = _take_batch(0, 8, 160)
+    text = jax.jit(jax.vmap(
+        lambda k, w, b: billing._take(k, w, b, cover))).lower(
+            key, weights, budget).as_text()
+    assert "sort" in text
+    assert "gather" not in text
+    assert "scatter" not in text
