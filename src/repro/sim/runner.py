@@ -657,8 +657,8 @@ def make_step(schedule: wl.Schedule | wl.JaxSchedule, cfg: SimConfig, *,
                     # at completion time (the same lateness rule as
                     # ``violation_rows``; never-finished work is only judged
                     # at the horizon) and this tick's billed spend.
-                    ticks_allowed = jnp.ceil(sched.d_requested / cfg.dt)
-                    late = (t - work.t_submit) - ticks_allowed
+                    late = (t - work.t_submit) - ticks_allowed(
+                        sched.d_requested, cfg.dt)
                     obs_viol = jnp.sum(
                         (done_now & sched.valid & (late > 1))
                         .astype(jnp.float32))
@@ -898,6 +898,22 @@ def cost_at_completion(work_final: WorkloadState, cum_cost: jnp.ndarray,
     return jnp.where(unfinished | (t_end < 0), cum_cost[-1], cum_cost[idx])
 
 
+def ticks_allowed(d_requested: jnp.ndarray, dt: float) -> jnp.ndarray:
+    """A TTC of ``d_requested`` seconds in monitoring ticks of ``dt``
+    seconds: ``ceil(d_requested / dt)``, exactly.
+
+    Inside ``jit`` XLA turns a division by a constant into a product with
+    its rounded reciprocal (6300 * f32(1/60) = 105.0000076, whose ceiling
+    is 106), so the rounded quotient is corrected by one comparison each
+    way against ``k * dt``.  That product is exact wherever it is a
+    float32 number: whole-second ``dt`` and TTCs under 2**24 s, or ``dt``
+    a power-of-two fraction of a second.
+    """
+    k = jnp.ceil(d_requested / dt)
+    k = jnp.where((k - 1.0) * dt >= d_requested, k - 1.0, k)
+    return jnp.where(k * dt < d_requested, k + 1.0, k)
+
+
 def violation_rows(work_final: WorkloadState,
                    schedule: wl.Schedule | wl.JaxSchedule,
                    cfg: SimConfig,
@@ -911,13 +927,13 @@ def violation_rows(work_final: WorkloadState,
     sched = wl.as_jax_schedule(schedule)
     if valid is None:
         valid = sched.valid
-    ticks_allowed = jnp.ceil(sched.d_requested / cfg.dt)
     submitted = (work_final.t_submit >= 0) & valid
     finished = work_final.t_done >= 0
     # Judged against the TTC *requested* at submission (with one tick of
     # grace).  A confirmed-but-extended deadline (infeasible request) is
     # therefore still counted as a violation of the original ask.
-    lateness = (work_final.t_done - work_final.t_submit) - ticks_allowed
+    lateness = ((work_final.t_done - work_final.t_submit)
+                - ticks_allowed(sched.d_requested, cfg.dt))
     return ((submitted & finished & (lateness > 1)) |
             (submitted & ~finished))
 
