@@ -419,8 +419,9 @@ def make_step(schedule: wl.Schedule | wl.JaxSchedule, cfg: SimConfig, *,
             if use_spot:
                 spot_state = spot_lib.step(state.spot, cfg.spot, cfg.dt,
                                            ema_alpha=pp.ema_alpha)
-                slot_price = spot_state.prices[cluster.itype]   # (I,)
-                cores = spot_lib.CORES_TABLE[cluster.itype]     # (I,) CUs/slot
+                # (I,) price and CUs of each slot's type.
+                slot_price = spot_lib.by_type(spot_state.prices, cluster.itype)
+                cores = spot_lib.by_type(spot_lib.CORES_TABLE, cluster.itype)
             else:
                 spot_state = state.spot
                 slot_price = None
@@ -575,8 +576,8 @@ def make_step(schedule: wl.Schedule | wl.JaxSchedule, cfg: SimConfig, *,
         with jax.named_scope("summary"):
             # Slots started this tick carry their new type; refresh the CU
             # weights before reporting control-plane sizes.
-            out_cores = (spot_lib.CORES_TABLE[cluster.itype] if use_spot
-                         else cores)
+            out_cores = (spot_lib.by_type(spot_lib.CORES_TABLE, cluster.itype)
+                         if use_spot else cores)
             n_committed = billing_lib.committed(cluster, out_cores)
             if fcfg is not None and use_spot:
                 # Fail-streak / backoff bookkeeping.  The streak counts

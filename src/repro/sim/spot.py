@@ -111,6 +111,22 @@ def fleet_mask(fleet: Sequence[str | int]) -> jnp.ndarray:
     return jnp.asarray(mask, jnp.float32)
 
 
+def by_type(table: jnp.ndarray, itype: jnp.ndarray) -> jnp.ndarray:
+    """``table[itype]`` for a (T,) per-type table and type ids of any shape.
+
+    A static select chain over the ``N_TYPES`` types, so it lowers to
+    elementwise compares and selects: under ``vmap`` with a batched table
+    the indexing form becomes a per-row gather, which the TPU runs as a
+    scalar loop.  Each result is the table entry itself, bit for bit
+    (``inf``, ``-0.0`` and ``nan`` included).  Ids must lie in
+    ``[0, N_TYPES)``.
+    """
+    out = table[0]
+    for k in range(1, N_TYPES):
+        out = jnp.where(itype == k, table[k], out)
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class SpotConfig:
     """Static knobs of the market process (closed over at trace time)."""
